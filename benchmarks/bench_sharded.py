@@ -19,59 +19,54 @@ Gates (assertion-enforced, so the suite fails loudly in CI):
   * **compile discipline** — <= num_buckets compile units TOTAL at every
     device count (the pmap program is shared by all lanes).
 
-Wall time is reported but NOT gated across device counts: the "devices"
-are XLA host-platform fakes sharing the same physical cores, so real
-wall scaling is not observable here — the modeled-launch metric is the
-honest scaling signal (it is exact on real accelerators, where lanes run
-concurrently).
+Wall time is reported but NOT gated across device counts: on a CPU the
+"devices" are XLA host-platform fakes sharing the same physical cores,
+so real wall scaling is not observable there — the modeled-launch metric
+is the honest scaling signal (it is exact on real accelerators, where
+lanes run concurrently).
 
-Each device count runs in a subprocess (``XLA_FLAGS=
---xla_force_host_platform_device_count=N``): the bench process itself
-must keep seeing 1 device, exactly like tests/test_distributed.py.
+Every device count runs in THIS process, through ``mesh_devices``, over
+the powers of two up to ``jax.local_device_count()``: one process holds
+all of a TPU host's chips, and a child could not reach a chip its
+parent holds.  To fake devices on a CPU, the caller sets ``XLA_FLAGS``
+for the whole run:
 
-    PYTHONPATH=src python -m benchmarks.bench_sharded [--quick]
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        PYTHONPATH=src python -m benchmarks.bench_sharded [--quick]
 """
 from __future__ import annotations
 
 import argparse
-import json
-import os
-import subprocess
-import sys
-import textwrap
-from pathlib import Path
+import hashlib
+import time
 
 from benchmarks.common import print_table, save_table
-
-REPO = Path(__file__).resolve().parent.parent
 
 #: the acceptance gate: modeled-launch speedup at 2 devices
 MIN_SPEEDUP_AT_2 = 1.6
 
-_WORKER = """
-    import hashlib, json, time
+
+def device_grid() -> list[int]:
+    """Powers of two up to the visible device count (at most 8)."""
     import jax
-    from repro.core import aig as A, gnn
-    from repro.core.features import groot_features
-    from repro.exec import build_partition_plan
+
+    visible = jax.local_device_count()
+    return [d for d in (1, 2, 4, 8) if d <= visible]
+
+
+def _run(params, plan, feats, num_nodes, capacity, devices) -> dict:
+    from repro.core import gnn
     from repro.mesh import ShardedStreamingExecutor, build_mesh_plan
 
-    bits, k, capacity, devices = {bits}, {k}, {capacity}, {devices}
-    d = A.csa_multiplier(bits)
-    g = d.to_edge_graph()
-    feats = groot_features(d)
-    params = gnn.init_params(gnn.GNNConfig(), jax.random.key(0))
-    plan = build_partition_plan(g, k, partitioner="multilevel", seed=0)
     mplan = build_mesh_plan(plan, devices, capacity)
-
     ex = ShardedStreamingExecutor(
         params, "ref", num_devices=devices, capacity=capacity)
     t0 = time.perf_counter()
     pred = ex.run_plan(plan, feats, gnn_cfg=gnn.GNNConfig())
     wall = time.perf_counter() - t0
-    print(json.dumps({{
+    return {
         "devices": devices,
-        "num_nodes": g.num_nodes,
+        "num_nodes": num_nodes,
         "num_buckets": plan.num_buckets,
         "batches": mplan.total_batches,
         "waves": len(mplan.waves),
@@ -85,32 +80,25 @@ _WORKER = """
         "device_s": ex.stats.device_s,
         "launches_per_s": ex.stats.launches / wall if wall else 0.0,
         "pred_sha": hashlib.sha256(pred.tobytes()).hexdigest()[:16],
-    }}))
-"""
-
-
-def _run_worker(bits: int, k: int, capacity: int, devices: int) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
-    env["PYTHONPATH"] = str(REPO / "src")
-    code = textwrap.dedent(_WORKER.format(
-        bits=bits, k=k, capacity=capacity, devices=devices
-    ))
-    proc = subprocess.run(
-        [sys.executable, "-c", code],
-        capture_output=True, text=True, env=env, timeout=1200, cwd=REPO,
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"sharded worker (devices={devices}) failed:\n"
-            f"STDOUT:\n{proc.stdout}\nSTDERR:\n{proc.stderr}"
-        )
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    }
 
 
 def bench_scaling(bits: int, k: int, capacity: int,
                   device_grid: list[int]) -> list[dict]:
-    rows = [_run_worker(bits, k, capacity, D) for D in device_grid]
+    import jax
+
+    from repro.core import aig as A, gnn
+    from repro.core.features import groot_features
+    from repro.exec import build_partition_plan
+
+    d = A.csa_multiplier(bits)
+    g = d.to_edge_graph()
+    feats = groot_features(d)
+    params = gnn.init_params(gnn.GNNConfig(), jax.random.key(0))
+    plan = build_partition_plan(g, k, partitioner="multilevel", seed=0)
+    rows = [
+        _run(params, plan, feats, g.num_nodes, capacity, D) for D in device_grid
+    ]
     for row in rows:
         row.update(bits=bits, k=k, capacity=capacity)
         row["lane_batches"] = "/".join(map(str, row["lane_batches"]))
@@ -148,7 +136,7 @@ def main(argv=None):
         bits, k, capacity = 64, 32, 2
     else:
         bits, k, capacity = 256, 16, 2
-    rows = bench_scaling(bits, k, capacity, [1, 2, 4, 8])
+    rows = bench_scaling(bits, k, capacity, device_grid())
     print_table(
         f"sharded scaling: csa-{bits}, k={k}, capacity={capacity} "
         f"(modeled-launch speedup gated >= {MIN_SPEEDUP_AT_2}x at 2 devices)",

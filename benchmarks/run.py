@@ -10,6 +10,15 @@ columns), and an embedded ``repro.obs`` report (per-stage wall times from
 a suite-scoped tracer, the process-counter delta, plan-cache hit rate) —
 so the bench trajectory accumulates machine-readable points run over run.
 
+Every suite runs in this one process, which holds every visible device:
+no suite starts a child that would need a chip this process holds.  The
+sharded suite streams over the powers of two up to
+``jax.local_device_count()``; to fake a mesh on a CPU, set ``XLA_FLAGS``
+for the whole run (``XLA_FLAGS=--xla_force_host_platform_device_count=8
+python -m benchmarks.run --suites sharded``).  The timings are those of
+whatever backend the process runs on: a CPU run times XLA's CPU backend
+and the Pallas interpreter, not a chip.
+
 The multi-pod dry-run + roofline tables are separate entry points
 (python -m repro.launch.dryrun / python -m repro.roofline.report) since
 they re-initialise jax with 512 host devices.
@@ -49,6 +58,7 @@ def main(argv=None):
         bench_verification,
     )
     from benchmarks import common
+    from repro.compile_cache import enable_compile_cache
     from repro.kernels.plan_cache import PLAN_CACHE
     from repro.obs import REGISTRY, Sampler, Tracer
     from repro.obs.flight import DUMP_DIR_ENV
@@ -60,6 +70,7 @@ def main(argv=None):
         common.ART.mkdir(parents=True, exist_ok=True)
         os.environ.setdefault(DUMP_DIR_ENV, str(common.ART))
 
+    enable_compile_cache()
     t0 = time.time()
     suites = [
         ("accuracy", "accuracy (Fig. 6/7)", bench_accuracy.main),

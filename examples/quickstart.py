@@ -10,6 +10,7 @@ Pallas kernel backends.
 import argparse
 
 from repro.api import Session, SessionConfig
+from repro.compile_cache import enable_compile_cache
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--quick", action="store_true",
@@ -22,6 +23,7 @@ ap.add_argument("--chaos", action="store_true",
                      "faults through the service path must be retried "
                      "away without changing the result")
 args = ap.parse_args()
+enable_compile_cache()
 BITS = 16 if args.quick else 32
 EPOCHS = 120 if args.quick else 300
 
@@ -51,7 +53,7 @@ print(f"\n   re-growth recovered +{(r_re.accuracy - r_no.accuracy)*100:.2f}% acc
 print(f"   memory reduced {(1 - r_re.peak_memory_bytes / r.unpartitioned_memory_bytes)*100:.1f}% vs unpartitioned")
 
 print("5) a device memory budget: the router partitions and streams to fit...")
-import jax  # noqa: E402 — only consulted for the device count
+import jax  # noqa: E402 — consulted for the device count and backend
 
 n_devices = jax.local_device_count()
 stream_mode = "sharded" if n_devices > 1 else "streamed"
@@ -88,7 +90,8 @@ else:
           "XLA_FLAGS=--xla_force_host_platform_device_count=4 to fake a "
           "mesh on CPU)")
 
-print("7) inference through the Pallas GROOT kernels (interpret mode)...")
+print("7) inference through the Pallas GROOT kernels "
+      f"({'interpreted' if jax.default_backend() == 'cpu' else 'compiled'})...")
 r_k = sess.options(backend="groot_fused").verify(
     bits=8 if args.quick else 16, verify=False
 )

@@ -196,6 +196,9 @@ def cmd_top(args) -> int:
 
 
 def main(argv: Optional[list] = None) -> int:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     argv = sys.argv[1:] if argv is None else list(argv)
     if argv and argv[0] == "serve":
         # hand everything (flags included) to the service CLI untouched —

@@ -314,16 +314,25 @@ def prepare(cfg: PipelineConfig, design=None) -> PreparedDesign:
             part, subs = _cut(k)
             if budgeted and subs:
                 # the estimate can undershoot real halo growth: validate the
-                # BUILT plan's packed peak and re-split finer until it fits
+                # BUILT plan's packed peak and re-split finer until it fits.
+                # A finer cut that does not shrink the peak ends the search
+                # (deep re-growth halos can cover most of the design): past
+                # that point every doubling costs a full re-cut and buys
+                # nothing, and the run streams at the best cut found
                 from repro.exec.plan import plan_from_subgraphs
 
-                while k < g.num_nodes and plan_from_subgraphs(
-                    subs, g.num_nodes
-                ).peak_batch_memory_bytes(
-                    cfg.gnn, cfg.stream_capacity
-                ) > cfg.memory_budget_bytes:
-                    k *= 2
-                    part, subs = _cut(k)
+                def _peak(subs):
+                    return plan_from_subgraphs(
+                        subs, g.num_nodes
+                    ).peak_batch_memory_bytes(cfg.gnn, cfg.stream_capacity)
+
+                peak = _peak(subs)
+                while k < g.num_nodes and peak > cfg.memory_budget_bytes:
+                    finer_part, finer_subs = _cut(2 * k)
+                    finer_peak = _peak(finer_subs)
+                    if finer_peak >= peak:
+                        break
+                    k, part, subs, peak = 2 * k, finer_part, finer_subs, finer_peak
             bfrac = boundary_edge_fraction(g, part)
             if not subs:  # empty graph: fall back to the unpartitioned path
                 subs = None

@@ -130,9 +130,14 @@ def simulation_check(aig: A.AIG, bits: int, signed: bool, n_vectors: int = 256, 
         a = np.arange(2**bits, dtype=np.int64)
         a, b = np.meshgrid(a, a)
         a, b = a.ravel(), b.ravel()
-    else:
+    elif bits < 63:
         a = rng.integers(0, 2**bits, n_vectors, dtype=np.int64)
         b = rng.integers(0, 2**bits, n_vectors, dtype=np.int64)
+    else:
+        # operands wider than an int64: Python ints built from random bits
+        weights = np.array([1 << i for i in range(bits)], dtype=object)
+        a = rng.integers(0, 2, (n_vectors, bits)).astype(object) @ weights
+        b = rng.integers(0, 2, (n_vectors, bits)).astype(object) @ weights
     pis = np.zeros((2 * bits, len(a)), dtype=bool)
     for i in range(bits):
         pis[i] = (a >> i) & 1

@@ -89,7 +89,7 @@ def flash_attention(
     softcap: float = 0.0,
     q_block: int = 256,
     kv_block: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """q: (BH, S, hd); k/v: (BH, T, hd) — GQA callers flatten (B, KV, G)
     into BH and broadcast k/v per group.  Returns (BH, S, hd)."""

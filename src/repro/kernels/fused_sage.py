@@ -13,7 +13,8 @@ For the GNN's memory-bound regime (arithmetic intensity of the SpMM is
 O(1) flops/byte) this is the dominant HBM-traffic term after the gather —
 see EXPERIMENTS.md §Perf (GROOT kernel iterations).
 
-Validated in interpret mode against ``ref.ell_block_reduce_ref @ W``.
+Validated in interpret mode against ``ref.ell_block_reduce_ref @ W``;
+compiled for a v5e by ``tests/test_tpu_compile.py``.
 """
 from __future__ import annotations
 
@@ -34,7 +35,9 @@ def _fused_kernel(msgs_ref, w_ref, o_ref, *, rows: int, deg: int):
     m = msgs_ref[...].astype(jnp.float32)
     agg = m.reshape(rows, deg, m.shape[-1]).sum(axis=1)
     o_ref[...] = jax.lax.dot(
-        agg, w_ref[...].astype(jnp.float32), preferred_element_type=o_ref.dtype
+        agg, w_ref[...].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=o_ref.dtype,
     )
 
 
@@ -44,7 +47,7 @@ def fused_ld_matmul(
     deg: int,
     rows_per_tile: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
     h_tile: int = F_TILE,
 ) -> jax.Array:
     """msgs: (R_pad * deg, F_pad); w_mat: (F_pad, H_pad) -> (R_pad, H_pad).
@@ -100,7 +103,11 @@ def _fused_kernel_grouped(msgs_ref, wg_ref, w_ref, o_ref, *, rows: int, deg: int
     acc = None
     for g in range(groups):  # static, tiny (2 or 4): unrolls on the MXU
         agg = (m * w[:, g][:, None]).reshape(rows, deg, m.shape[-1]).sum(axis=1)
-        part = jax.lax.dot(agg, w_ref[g], preferred_element_type=o_ref.dtype)
+        part = jax.lax.dot(
+            agg, w_ref[g],
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=o_ref.dtype,
+        )
         acc = part if acc is None else acc + part
     o_ref[...] = acc
 
@@ -112,7 +119,7 @@ def fused_ld_matmul_grouped(
     deg: int,
     rows_per_tile: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
     h_tile: int = F_TILE,
 ) -> jax.Array:
     """msgs: (R_pad*deg, F_pad); wg: (R_pad*deg, G); w_stack: (G, F_pad, H_pad)

@@ -34,8 +34,11 @@ tile feeding the VPU/MXU.
 Thresholds mirror the paper: ``E_T = 512`` — rows with degree > 512 take
 the HD path, everything else lands in an LD power-of-2 bucket (1..512).
 
-All kernels are validated in ``interpret=True`` mode against
-``kernels/ref.py`` (CPU container; TPU is the target).
+Every entry point takes ``interpret`` explicitly; the kernel backends
+choose it in one place (:func:`repro.kernels.ops.pallas_interpret`): the
+Pallas interpreter on a CPU backend, compiled Mosaic kernels on the TPU.
+The interpret-mode tests validate against ``kernels/ref.py``;
+``tests/test_tpu_compile.py`` compiles every kernel for a v5e.
 """
 from __future__ import annotations
 
@@ -55,6 +58,12 @@ E_T = 512
 F_TILE = 128           # lane dimension tile (TPU lane width)
 LD_TILE_EDGES = 2048   # target edges per LD VMEM tile (R_t * d)
 SUBLANE = 8            # f32 sublane quantum
+# Row tile of the MXU LD reduction.  Its one-hot (R, R * d) matrix grows
+# with R^2 * d: at the VPU tile's R = 1024 rows and d = 2 it is 8 MiB,
+# which double-buffered overflows the 16 MiB of scoped VMEM on a v5e.
+# One MXU pass of rows bounds it to a few hundred KiB; every LD row tile
+# is a power of two, so this one divides it.
+MXU_ROWS = 128
 
 
 # ---------------------------------------------------------------------------
@@ -138,6 +147,7 @@ class HdPlan:
     cols: np.ndarray        # (n_chunks * E_t,) int32 source ids (pad = N)
     eids: np.ndarray        # (n_chunks * E_t,) int32 edge ids (pad = E)
     chunk_meta: np.ndarray  # (n_chunks, 2) int32: [output row slot, is_first]
+                            # (prefetched flattened by the HD kernels)
 
     @property
     def num_chunks(self) -> int:
@@ -322,7 +332,9 @@ def _ld_kernel_mxu(red_ref, msgs_ref, o_ref):
     becomes a systolic matmul (DESIGN.md §2, "one-hot MXU matmul").
     """
     o_ref[...] = jax.lax.dot(
-        red_ref[...], msgs_ref[...], preferred_element_type=o_ref.dtype
+        red_ref[...], msgs_ref[...],
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=o_ref.dtype,
     )
 
 
@@ -333,10 +345,11 @@ def ld_bucket_apply(
     PROBE["pallas_calls"] += 1
     f_pad = msgs.shape[1]
     r_pad = msgs.shape[0] // deg
-    r_t = rows_per_tile
+    mxu = mxu and deg > 1
+    r_t = min(rows_per_tile, MXU_ROWS) if mxu else rows_per_tile
     grid = (r_pad // r_t, f_pad // F_TILE)
     out_shape = jax.ShapeDtypeStruct((r_pad, f_pad), jnp.float32)
-    if mxu and deg > 1:
+    if mxu:
         red = np.zeros((r_t, r_t * deg), dtype=np.float32)
         for r in range(r_t):
             red[r, r * deg : (r + 1) * deg] = 1.0
@@ -370,16 +383,18 @@ def _hd_kernel(meta_ref, msgs_ref, o_ref):
 
     Chunks of the same row are consecutive in the (inner) chunk grid dim,
     so the output block stays resident in VMEM across the row's chunks —
-    the TPU version of the 32-warp row split + shuffle reduce.
+    the TPU version of the 32-warp row split + shuffle reduce.  ``meta``
+    is the flattened ``chunk_meta``: ``meta[2c]`` is chunk c's output row
+    slot, ``meta[2c + 1]`` is 1 on the row's first chunk.
     """
     c = pl.program_id(1)
     part = msgs_ref[...].astype(jnp.float32).sum(axis=0, keepdims=True)
 
-    @pl.when(meta_ref[c, 1] == 1)
+    @pl.when(meta_ref[2 * c + 1] == 1)
     def _init():
         o_ref[...] = part
 
-    @pl.when(meta_ref[c, 1] == 0)
+    @pl.when(meta_ref[2 * c + 1] == 0)
     def _acc():
         o_ref[...] += part
 
@@ -396,7 +411,11 @@ def hd_apply(
 
     Grid is (F-tiles, chunks): the chunk dim is innermost so same-row
     chunks revisit the same output block back-to-back (required for the
-    VMEM accumulation pattern).
+    VMEM accumulation pattern).  The output is laid out (n_hd, 1, F_pad)
+    so the block's last two dims, (1, F_TILE), are legal Mosaic tiles (a
+    (1, F_TILE) block over (n_hd, F_pad) is not); ``chunk_meta`` is
+    prefetched flat, since a 2-D SMEM operand pads its minor dim to 128
+    words and overflows SMEM at a few thousand chunks.
     """
     PROBE["pallas_calls"] += 1
     f_pad = msgs.shape[1]
@@ -405,14 +424,17 @@ def hd_apply(
         num_scalar_prefetch=1,
         grid=(f_pad // F_TILE, n_chunks),
         in_specs=[pl.BlockSpec((e_t, F_TILE), lambda j, c, meta: (c, j))],
-        out_specs=pl.BlockSpec((1, F_TILE), lambda j, c, meta: (meta[c, 0], j)),
+        out_specs=pl.BlockSpec(
+            (pl.Squeezed(), 1, F_TILE), lambda j, c, meta: (meta[2 * c], 0, j)
+        ),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _hd_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_hd_rows, f_pad), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_hd_rows, 1, f_pad), jnp.float32),
         interpret=interpret,
-    )(jnp.asarray(chunk_meta), msgs)
+    )(jnp.asarray(chunk_meta.reshape(-1)), msgs)
+    return out.reshape(n_hd_rows, f_pad)
 
 
 # ---------------------------------------------------------------------------
@@ -457,7 +479,7 @@ def apply_plan(
     x: jax.Array,
     w: Optional[jax.Array] = None,
     *,
-    interpret: bool = True,
+    interpret: bool,
     mxu: bool = False,
 ) -> jax.Array:
     """Compute ``out[r] = sum_{e: dst[e]=r} w[e] * x[src[e]]`` via the
@@ -531,7 +553,11 @@ def _ld_kernel_grouped_mxu(red_ref, wg_ref, msgs_ref, o_ref, *, groups: int):
     red = red_ref[...]
     o_ref[...] = jnp.stack(
         [
-            jax.lax.dot(red, m * w[:, g][:, None], preferred_element_type=o_ref.dtype)
+            jax.lax.dot(
+                red, m * w[:, g][:, None],
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=o_ref.dtype,
+            )
             for g in range(groups)
         ],
         axis=0,
@@ -555,10 +581,11 @@ def ld_grouped_apply(
     f_pad = msgs.shape[1]
     g = wg.shape[1]
     r_pad = msgs.shape[0] // deg
-    r_t = rows_per_tile
+    mxu = mxu and deg > 1
+    r_t = min(rows_per_tile, MXU_ROWS) if mxu else rows_per_tile
     grid = (r_pad // r_t, f_pad // F_TILE)
     out_shape = jax.ShapeDtypeStruct((g, r_pad, f_pad), jnp.float32)
-    if mxu and deg > 1:
+    if mxu:
         red = np.zeros((r_t, r_t * deg), dtype=np.float32)
         for r in range(r_t):
             red[r, r * deg : (r + 1) * deg] = 1.0
@@ -591,18 +618,22 @@ def _hd_kernel_grouped(meta_ref, wg_ref, msgs_ref, o_ref):
     """One E_t-edge chunk -> per-group partial sums for the chunk's row.
 
     The weighted reduction is one (G, E_t) @ (E_t, F_t) systolic matmul;
-    accumulation across a row's chunks revisits the same (G, 1, F_t)
-    output block in VMEM, exactly like the ungrouped HD kernel."""
+    accumulation across a row's chunks revisits the same (G, F_t) output
+    block in VMEM, exactly like the ungrouped HD kernel."""
     c = pl.program_id(1)
     m = msgs_ref[...].astype(jnp.float32)
     w = wg_ref[...].astype(jnp.float32)
-    part = jax.lax.dot(w.T, m, preferred_element_type=jnp.float32)[:, None, :]
+    part = jax.lax.dot_general(
+        w, m, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
 
-    @pl.when(meta_ref[c, 1] == 1)
+    @pl.when(meta_ref[2 * c + 1] == 1)
     def _init():
         o_ref[...] = part
 
-    @pl.when(meta_ref[c, 1] == 0)
+    @pl.when(meta_ref[2 * c + 1] == 0)
     def _acc():
         o_ref[...] += part
 
@@ -617,7 +648,11 @@ def hd_grouped_apply(
     interpret: bool,
 ) -> jax.Array:
     """msgs: (n_chunks * e_t, F_pad); wg: (n_chunks * e_t, G)
-    -> (G, n_hd_rows, F_pad)."""
+    -> (G, n_hd_rows, F_pad).
+
+    The kernel writes row-major (n_hd, G, F_pad) — a (G, F_TILE) block is
+    a legal tile where (G, 1, F_TILE) over (G, n_hd, F_pad) is not — and
+    the group-major layout is restored outside (HD rows are few)."""
     PROBE["pallas_calls"] += 1
     f_pad = msgs.shape[1]
     g = wg.shape[1]
@@ -629,14 +664,17 @@ def hd_grouped_apply(
             pl.BlockSpec((e_t, g), lambda j, c, meta: (c, 0)),
             pl.BlockSpec((e_t, F_TILE), lambda j, c, meta: (c, j)),
         ],
-        out_specs=pl.BlockSpec((g, 1, F_TILE), lambda j, c, meta: (0, meta[c, 0], j)),
+        out_specs=pl.BlockSpec(
+            (pl.Squeezed(), g, F_TILE), lambda j, c, meta: (meta[2 * c], 0, j)
+        ),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _hd_kernel_grouped,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((g, n_hd_rows, f_pad), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_hd_rows, g, f_pad), jnp.float32),
         interpret=interpret,
-    )(jnp.asarray(chunk_meta), wg, msgs)
+    )(jnp.asarray(chunk_meta.reshape(-1)), wg, msgs)
+    return jnp.transpose(out, (1, 0, 2))
 
 
 # ---------------------------------------------------------------------------
@@ -705,7 +743,7 @@ def apply_plan_grouped_staged(
     x_p: jax.Array,
     staged: StagedWeights,
     *,
-    interpret: bool = True,
+    interpret: bool,
     mxu: bool = False,
 ) -> jax.Array:
     """Hoisted grouped walk: pre-padded features (see :func:`pad_features`)
@@ -740,7 +778,7 @@ def apply_plan_grouped(
     x: jax.Array,
     wg: jax.Array,
     *,
-    interpret: bool = True,
+    interpret: bool,
     mxu: bool = False,
 ) -> jax.Array:
     """All-groups SpMM: ``out[g, r] = sum_{e: dst[e]=r} wg[e, g] * x[src[e]]``.

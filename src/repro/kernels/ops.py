@@ -9,8 +9,9 @@ module builds them for each backend:
                   GNNAdvisor-style baseline)
   ``onehot``      dense one-hot matmul formulation (cuSPARSE-dense
                   analogue; O(N*E) — small graphs/benchmarks only)
-  ``groot``       the Pallas degree-bucketed HD/LD kernels (VPU reduce),
-                  interpret=True on CPU
+  ``groot``       the Pallas degree-bucketed HD/LD kernels (VPU reduce);
+                  compiled on the TPU, interpreted on a CPU backend
+                  (:func:`pallas_interpret`)
   ``groot_mxu``   same, LD reduction as one-hot block-diag MXU matmul
   ``groot_fused`` ``groot`` aggregation whose LD slabs can additionally be
                   fused with the following weight matmul
@@ -153,6 +154,14 @@ def _onehot_pair(edge_src, edge_dst, num_nodes) -> AggPair:
     )
 
 
+def pallas_interpret() -> bool:
+    """Whether the GROOT Pallas kernels run in interpret mode — the one
+    place that decides it.  Only a CPU backend interprets; on the TPU the
+    kernels are compiled, and a kernel that fails to compile raises (there
+    is no fallback to the interpreter or to ``ref``)."""
+    return jax.default_backend() == "cpu"
+
+
 def _groot_pair(
     edge_src,
     edge_dst,
@@ -160,7 +169,7 @@ def _groot_pair(
     *,
     mxu: bool,
     fused: bool,
-    interpret: bool = True,
+    interpret: bool,
     use_cache: bool = True,
 ) -> AggPair:
     src = np.asarray(edge_src)
@@ -402,16 +411,17 @@ def _build_pair(edge_src, edge_dst, num_nodes: int, backend: str,
         return _segment_pair(edge_src, edge_dst, num_nodes)
     if backend == "onehot":
         return _onehot_pair(edge_src, edge_dst, num_nodes)
-    if backend == "groot":
-        return _groot_pair(edge_src, edge_dst, num_nodes, mxu=False, fused=False,
-                           use_cache=use_cache)
-    if backend == "groot_mxu":
-        return _groot_pair(edge_src, edge_dst, num_nodes, mxu=True, fused=False,
-                           use_cache=use_cache)
-    if backend == "groot_fused":
-        return _groot_pair(edge_src, edge_dst, num_nodes, mxu=False, fused=True,
-                           use_cache=use_cache)
-    raise ValueError(f"unknown backend {backend!r} (want one of {BACKENDS})")
+    variants = {
+        "groot": dict(mxu=False, fused=False),
+        "groot_mxu": dict(mxu=True, fused=False),
+        "groot_fused": dict(mxu=False, fused=True),
+    }
+    if backend not in variants:
+        raise ValueError(f"unknown backend {backend!r} (want one of {BACKENDS})")
+    return _groot_pair(
+        edge_src, edge_dst, num_nodes, **variants[backend],
+        interpret=pallas_interpret(), use_cache=use_cache,
+    )
 
 
 def make_agg_pair(

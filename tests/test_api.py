@@ -167,6 +167,22 @@ def test_router_memory_budget_streams_to_fit(rand_params):
     assert r.exec_stats["peak_packed_memory_bytes"] == d.modeled_peak_bytes
 
 
+def test_unmeetable_budget_stops_refining(rand_params):
+    """Re-grown by num_layers hops, every partition of a CSA multiplier
+    keeps most of the design, so no cut meets half the full-graph model.
+    The re-split search must stop once a finer cut stops shrinking the
+    packed peak; it used to double k up to one partition per node,
+    re-cutting at every step, which never returns at csa:512."""
+    sess = Session(rand_params, SessionConfig(
+        dataset="csa", bits=32, regrow_hops=4
+    ))
+    full = sess.explain().modeled_full_bytes
+    tight = sess.options(memory_budget_bytes=full // 2)
+    d = tight.explain()
+    assert d.mode == "streamed" and 1 < d.k <= 64
+    assert d.modeled_peak_bytes > full // 2       # streams at the best cut
+
+
 def test_repeated_verify_builds_zero_plans_zero_compiles(rand_params):
     """Same-structure designs through a session: the second run touches
     neither the structural plan cache (0 builds) nor jit (0 compiles)."""

@@ -117,20 +117,27 @@ def test_assembly_index_is_inverse_count_sort(case):
 
 @pytest.mark.parametrize("case", MIXTURES[:2])
 def test_scatter_free_assembly_matches_scatter(case):
-    """Gather-based assembly == the pre-hoist ``at[rows].add`` bit for bit."""
+    """The grouped walk's gather assembly == the ungrouped walk's, to
+    within f32 rounding of the same sums."""
     src, dst, n, e_t = _mixture(case)
     e = len(src)
     rng = np.random.default_rng(5)
     x = jnp.asarray(rng.standard_normal((n, 8)), jnp.float32)
     w = jnp.asarray(rng.standard_normal(e), jnp.float32)
     plan = build_plan(src, dst, n, e_t=e_t)
-    got = np.asarray(apply_plan(plan, x, w))
+    got = np.asarray(apply_plan(plan, x, w, interpret=True))
     wg = jnp.stack([w, 2.0 * w], axis=1)
-    grouped = np.asarray(apply_plan_grouped(plan, x, wg))
+    grouped = np.asarray(apply_plan_grouped(plan, x, wg, interpret=True))
     if plan.hd is None:
-        # identical LD kernel reductions -> assembly is pure data
-        # movement: bit-exact
-        np.testing.assert_array_equal(grouped[0], got)
+        # assembly is pure data movement, but the ungrouped walk weights
+        # the messages before the LD kernel and the grouped one inside
+        # it, so the compiler may contract multiply and add into an FMA
+        # in one form and not the other (jax 0.9's CPU backend does):
+        # the same sums then differ in the last bits.  A few f32 ulps of
+        # the output scale bound that; an assembly fault misplaces whole
+        # rows, orders of magnitude above it.
+        ulps = 8 * np.finfo(np.float32).eps * np.abs(got).max()
+        np.testing.assert_allclose(grouped[0], got, rtol=0, atol=ulps)
     else:
         # the grouped HD kernel reduces via matmul (different reduction
         # order than the ungrouped sum) — tolerance, not bits
@@ -234,7 +241,7 @@ def test_grouped_walks_handle_zero_edge_graph():
     plan = build_plan(np.zeros(0, np.int64), np.zeros(0, np.int64), n)
     x = jnp.asarray(np.random.default_rng(0).standard_normal((n, 3)), jnp.float32)
     wg = jnp.zeros((0, g), jnp.float32)
-    out = apply_plan_grouped(plan, x, wg)
+    out = apply_plan_grouped(plan, x, wg, interpret=True)
     assert out.shape == (g, n, 3)
     np.testing.assert_array_equal(np.asarray(out), 0.0)
 

@@ -68,10 +68,10 @@ def test_apply_plan_grouped_matches_per_group(n, e_ld, hd_rows, f, g, mxu):
     x = jnp.asarray(rng.standard_normal((n, f)), jnp.float32)
     wg = jnp.asarray(rng.standard_normal((e, g)), jnp.float32)
     plan = build_plan(src, dst, n)
-    got = apply_plan_grouped(plan, x, wg, mxu=mxu)
+    got = apply_plan_grouped(plan, x, wg, interpret=True, mxu=mxu)
     assert got.shape == (g, n, f) and got.dtype == x.dtype
     for k in range(g):
-        want = apply_plan(plan, x, wg[:, k], mxu=mxu)
+        want = apply_plan(plan, x, wg[:, k], interpret=True, mxu=mxu)
         np.testing.assert_allclose(
             np.asarray(got[k]), np.asarray(want), rtol=1e-4, atol=1e-4
         )
@@ -83,7 +83,7 @@ def test_apply_plan_grouped_bf16_accumulates_f32():
     x = jnp.asarray(rng.standard_normal((200, 32)), jnp.bfloat16)
     wg = jnp.asarray(rng.standard_normal((len(src), 4)), jnp.float32)
     plan = build_plan(src, dst, 200)
-    got = apply_plan_grouped(plan, x, wg)
+    got = apply_plan_grouped(plan, x, wg, interpret=True)
     assert got.dtype == jnp.bfloat16
     xf = x.astype(jnp.float32)
     deg_max = int(np.bincount(dst, minlength=200).max())
@@ -101,7 +101,9 @@ def test_fused_grouped_kernel_matches_ref():
     msgs = jnp.asarray(rng.standard_normal((r * deg, f)), jnp.float32)
     wg = jnp.asarray(rng.standard_normal((r * deg, g)), jnp.float32)
     w_stack = jnp.asarray(rng.standard_normal((g, f, h)), jnp.float32)
-    got = fused_ld_matmul_grouped(msgs, wg, w_stack, deg, rows_per_tile=16)
+    got = fused_ld_matmul_grouped(
+        msgs, wg, w_stack, deg, rows_per_tile=16, interpret=True
+    )
     want = fused_grouped_ref(msgs, wg, w_stack, deg)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
 

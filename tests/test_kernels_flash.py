@@ -26,7 +26,7 @@ def _mk(bh, s, t, hd, seed=0, dtype=jnp.float32):
 def test_flash_causal_matches_ref(s, t, qb, kb, window):
     q, k, v = _mk(4, s, t, 64)
     got = flash_attention(q, k, v, causal=True, window=window,
-                          q_block=qb, kv_block=kb)
+                          q_block=qb, kv_block=kb, interpret=True)
     want = flash_ref(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -34,7 +34,8 @@ def test_flash_causal_matches_ref(s, t, qb, kb, window):
 
 def test_flash_bidirectional():
     q, k, v = _mk(2, 256, 256, 64)
-    got = flash_attention(q, k, v, causal=False, q_block=128, kv_block=128)
+    got = flash_attention(q, k, v, causal=False, q_block=128, kv_block=128,
+                          interpret=True)
     want = flash_ref(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -42,7 +43,8 @@ def test_flash_bidirectional():
 
 def test_flash_softcap():
     q, k, v = _mk(2, 128, 128, 32, seed=3)
-    got = flash_attention(q, k, v, softcap=20.0, q_block=64, kv_block=64)
+    got = flash_attention(q, k, v, softcap=20.0, q_block=64, kv_block=64,
+                          interpret=True)
     want = flash_ref(q, k, v, softcap=20.0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -50,7 +52,7 @@ def test_flash_softcap():
 
 def test_flash_bf16():
     q, k, v = _mk(2, 256, 256, 64, seed=5, dtype=jnp.bfloat16)
-    got = flash_attention(q, k, v, q_block=128, kv_block=128)
+    got = flash_attention(q, k, v, q_block=128, kv_block=128, interpret=True)
     want = flash_ref(q.astype(jnp.float32), k.astype(jnp.float32),
                      v.astype(jnp.float32))
     assert got.dtype == jnp.bfloat16
@@ -81,7 +83,8 @@ def test_flash_matches_model_sdpa():
     qf = jnp.moveaxis(q.reshape(b, s, kv, g, hd), 1, 3).reshape(b * kv * g, s, hd)
     kf = jnp.repeat(jnp.moveaxis(k, 1, 2), g, axis=1).reshape(b * kv * g, s, hd)
     vf = jnp.repeat(jnp.moveaxis(v, 1, 2), g, axis=1).reshape(b * kv * g, s, hd)
-    of = flash_attention(qf, kf, vf, causal=True, q_block=128, kv_block=128)
+    of = flash_attention(qf, kf, vf, causal=True, q_block=128, kv_block=128,
+                         interpret=True)
     out_k = jnp.moveaxis(of.reshape(b, kv, g, s, hd), 3, 1).reshape(b, s, h, hd)
     out_kernel = jnp.einsum("bshk,hkd->bsd", out_k, p["wo"])
     np.testing.assert_allclose(np.asarray(out_kernel), np.asarray(out_model),

@@ -30,14 +30,20 @@ def trained_params():
 # Generators are functionally correct multipliers
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("bits", [2, 4, 6])
+# 96 bits: operands wider than an int64 (random vectors, bignum spec)
+@pytest.mark.parametrize("bits", [2, 4, 6, 96])
 def test_csa_multiplier_functional(bits):
     assert simulation_check(A.csa_multiplier(bits), bits, signed=False)
 
 
-@pytest.mark.parametrize("bits", [2, 4, 6])
+@pytest.mark.parametrize("bits", [2, 4, 6, 96])
 def test_booth_multiplier_functional(bits):
     assert simulation_check(A.booth_multiplier(bits), bits, signed=True)
+
+
+def test_wide_simulation_check_rejects_the_wrong_spec():
+    # an unsigned multiplier is not a signed one: the bignum path must see it
+    assert not simulation_check(A.csa_multiplier(96), 96, signed=True)
 
 
 def test_mapped_multiplier_functional():
