@@ -77,7 +77,7 @@ def _run(params, plan, feats, num_nodes, capacity, devices) -> dict:
         "launches": ex.stats.launches,
         "wall_s": wall,
         "pack_s": ex.stats.pack_s,
-        "device_s": ex.stats.device_s,
+        "launch_s": ex.stats.launch_s,
         "launches_per_s": ex.stats.launches / wall if wall else 0.0,
         "pred_sha": hashlib.sha256(pred.tobytes()).hexdigest()[:16],
     }

@@ -55,6 +55,7 @@ from repro.obs import (
     current_tracer,
     fold_into,
     record_from_marks,
+    span,
 )
 from repro.service.cache import ResultCache
 
@@ -359,7 +360,9 @@ class Session:
         elif isinstance(design, A.AIG):
             from repro.io import aiger
 
-            h = aiger.structural_hash(design)
+            with span("parse.hash") as sp:
+                h, size = aiger.structural_digest(design)
+                sp.set(bytes=size)
         else:
             return None
         return ResultCache.key(

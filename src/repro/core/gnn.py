@@ -29,6 +29,7 @@ import numpy as np
 from repro.core import aig as A
 from repro.core.graph import EdgeGraph
 from repro.core.regrowth import Subgraph
+from repro.kernels.plan_cache import PLAN_CACHE
 from repro.obs import REGISTRY, span
 from repro.training import optimizer as opt
 
@@ -167,14 +168,15 @@ def forward(
         )
 
     deg = lambda idx, w: jax.ops.segment_sum(w, idx, num_segments=num_nodes)
-    norm_in = {
-        nm: (1.0 / jnp.maximum(deg(edge_dst, w), 1.0))[:, None]
-        for nm, w in group_w.items()
-    }
-    norm_out = {
-        nm: (1.0 / jnp.maximum(deg(edge_src, w), 1.0))[:, None]
-        for nm, w in out_w.items()
-    }
+    with jax.named_scope("gnn.degree"):
+        norm_in = {
+            nm: (1.0 / jnp.maximum(deg(edge_dst, w), 1.0))[:, None]
+            for nm, w in group_w.items()
+        }
+        norm_out = {
+            nm: (1.0 / jnp.maximum(deg(edge_src, w), 1.0))[:, None]
+            for nm, w in out_w.items()
+        }
 
     if agg is None:
         in_agg = lambda h, w: segment_sum_agg(h, edge_src, edge_dst, num_nodes, w)
@@ -184,7 +186,8 @@ def forward(
         in_agg, out_agg, in_agg_mm = agg.in_agg, agg.out_agg, agg.in_agg_mm
 
     if in_agg_mm is not None:  # fused path: fold norms into edge weights
-        group_w = {nm: w * norm_in[nm][:, 0][edge_dst] for nm, w in group_w.items()}
+        with jax.named_scope("gnn.degree"):
+            group_w = {nm: w * norm_in[nm][:, 0][edge_dst] for nm, w in group_w.items()}
 
     h = x
     for layer in params["layers"]:
@@ -197,7 +200,13 @@ def forward(
         for nm in OUT_GROUPS:
             acc = acc + (out_agg(h, out_w[nm]) * norm_out[nm]) @ layer[nm]
         h = jax.nn.relu(acc)
-    return h @ params["head"]["w"] + params["head"]["b"]
+    return _head(params, h)
+
+
+def _head(params, h):
+    """The classifier head, under the ``gnn.head`` scope."""
+    with jax.named_scope("gnn.head"):
+        return h @ params["head"]["w"] + params["head"]["b"]
 
 
 def _forward_grouped(params, x, edge_src, edge_dst, group_w, out_w, num_nodes, agg,
@@ -218,10 +227,11 @@ def _forward_grouped(params, x, edge_src, edge_dst, group_w, out_w, num_nodes, a
     wg_out = jnp.stack([out_w[nm] for nm in OUT_GROUPS], axis=1)     # (E, 2)
     # per-group in/out degrees in ONE segment-sum per direction (the
     # per-group path needs six)
-    deg_in = jax.ops.segment_sum(wg_in, edge_dst, num_segments=num_nodes)
-    deg_out = jax.ops.segment_sum(wg_out, edge_src, num_segments=num_nodes)
-    wg_in = wg_in * (1.0 / jnp.maximum(deg_in, 1.0))[edge_dst]
-    wg_out = wg_out * (1.0 / jnp.maximum(deg_out, 1.0))[edge_src]
+    with jax.named_scope("gnn.degree"):
+        deg_in = jax.ops.segment_sum(wg_in, edge_dst, num_segments=num_nodes)
+        deg_out = jax.ops.segment_sum(wg_out, edge_src, num_segments=num_nodes)
+        wg_in = wg_in * (1.0 / jnp.maximum(deg_in, 1.0))[edge_dst]
+        wg_out = wg_out * (1.0 / jnp.maximum(deg_out, 1.0))[edge_src]
 
     fp = getattr(agg, "fwd_plan", None)
     if fp is not None and agg.in_agg_staged is not None:
@@ -240,7 +250,7 @@ def _forward_grouped(params, x, edge_src, edge_dst, group_w, out_w, num_nodes, a
         gout = agg.out_agg_grouped(h, wg_out)                        # (2, N, F)
         acc = acc + jnp.einsum("gnf,gfh->nh", gout.astype(acc.dtype), w_out_stack)
         h = jax.nn.relu(acc)
-    return h @ params["head"]["w"] + params["head"]["b"]
+    return _head(params, h)
 
 
 def _forward_hoisted(params, x, wg_in, wg_out, agg, fp, stream_dtype):
@@ -263,8 +273,9 @@ def _forward_hoisted(params, x, wg_in, wg_out, agg, fp, stream_dtype):
     sdt = None
     if stream_dtype is not None and jnp.dtype(stream_dtype) != jnp.float32:
         sdt = jnp.dtype(stream_dtype)
-    sw_in = fp.stage_in(wg_in, dtype=sdt)
-    sw_out = fp.stage_out(wg_out, dtype=sdt)
+    with jax.named_scope("gnn.stage"):
+        sw_in = fp.stage_in(wg_in, dtype=sdt)
+        sw_out = fp.stage_out(wg_out, dtype=sdt)
     layers = params["layers"]
     fused = agg.in_agg_mm_staged is not None
     stacks_in = [jnp.stack([l[nm] for nm in IN_GROUPS], axis=0) for l in layers]
@@ -289,7 +300,7 @@ def _forward_hoisted(params, x, wg_in, wg_out, agg, fp, stream_dtype):
         gout = agg.out_agg_staged(h_p, sw_out)[:, :, :f]             # (2, N, F)
         acc = acc + jnp.einsum("gnf,gfh->nh", gout.astype(acc.dtype), w_out_stack)
         h = jax.nn.relu(acc)
-    return h @ params["head"]["w"] + params["head"]["b"]
+    return _head(params, h)
 
 
 def loss_fn(params, batch):
@@ -372,33 +383,42 @@ def _make_agg(g, backend: str):
     return ops.make_agg_pair(g.edge_src, g.edge_dst, g.num_nodes, backend)
 
 
+def _stage(arrays):
+    """Host -> device for ``arrays`` (None entries stay None), and the
+    bytes handed to the device."""
+    staged = jax.device_put(arrays)
+    return staged, sum(int(a.nbytes) for a in staged if a is not None)
+
+
 def predict(
     params, design, features, backend: str = "ref",
     *, stream_dtype: Optional[str] = None,
 ) -> np.ndarray:
+    """Full-graph predictions, in four spans: ``execute.plan`` (the
+    aggregation pair, a plan-cache hit or a build), ``execute.stage``
+    (host -> device of the features and edge arrays), ``execute.run``
+    (dispatch until the device is done) and ``execute.fetch``
+    (device -> host of the predictions)."""
     g = design.to_edge_graph() if hasattr(design, "to_edge_graph") else design
-    inv = None if g.edge_inv is None else jnp.asarray(g.edge_inv)
-    slot = None if g.edge_slot is None else jnp.asarray(g.edge_slot)
-    feats = np.asarray(features)
-    # staged h2d bytes: features + the edge index/annotation arrays
-    REGISTRY.counter("gnn.bytes_staged").inc(
-        feats.nbytes + 2 * g.edge_src.nbytes + 2 * g.edge_dst.nbytes
-    )
-    with span("gnn.predict", backend=backend, nodes=g.num_nodes):
-        REGISTRY.counter("gnn.predicts").inc()
-        return np.asarray(
-            _predict(
-                params,
-                jnp.asarray(feats),
-                jnp.asarray(g.edge_src),
-                jnp.asarray(g.edge_dst),
-                inv,
-                slot,
-                g.num_nodes,
-                _make_agg(g, backend),
-                stream_dtype,
-            )
+    REGISTRY.counter("gnn.predicts").inc()
+    with span("execute.plan", backend=backend, nodes=g.num_nodes) as sp:
+        builds = PLAN_CACHE.stats.builds
+        agg = _make_agg(g, backend)
+        if agg is not None:
+            sp.set(hit=PLAN_CACHE.stats.builds == builds)
+    with span("execute.stage") as sp:
+        staged, nbytes = _stage(
+            (np.asarray(features), g.edge_src, g.edge_dst, g.edge_inv, g.edge_slot)
         )
+        x, src, dst, inv, slot = jax.block_until_ready(staged)
+        sp.set(h2d_bytes=nbytes)
+    REGISTRY.counter("gnn.bytes_staged").inc(nbytes)
+    with span("execute.run"):
+        out = jax.block_until_ready(_predict(
+            params, x, src, dst, inv, slot, g.num_nodes, agg, stream_dtype,
+        ))
+    with span("execute.fetch"):
+        return np.asarray(out)
 
 
 def predict_partitioned(
@@ -464,23 +484,15 @@ def predict_partitioned_loop(
     """
     out = np.zeros(num_nodes, dtype=np.int32)
     for sg in subgraphs:
-        feats = jnp.asarray(features[sg.global_ids])
-        inv = None if sg.edge_inv is None else jnp.asarray(sg.edge_inv)
-        slot = None if sg.edge_slot is None else jnp.asarray(sg.edge_slot)
-        REGISTRY.counter("gnn.loop_launches").inc()
-        REGISTRY.counter("gnn.bytes_staged").inc(
-            int(feats.nbytes) + 2 * sg.edge_src.nbytes + 2 * sg.edge_dst.nbytes
+        (feats, src, dst, inv, slot), nbytes = _stage(
+            (features[sg.global_ids], sg.edge_src, sg.edge_dst, sg.edge_inv,
+             sg.edge_slot)
         )
+        REGISTRY.counter("gnn.loop_launches").inc()
+        REGISTRY.counter("gnn.bytes_staged").inc(nbytes)
         pred = _predict(
-            params,
-            feats,
-            jnp.asarray(sg.edge_src),
-            jnp.asarray(sg.edge_dst),
-            inv,
-            slot,
-            sg.num_nodes,
-            _make_agg(sg.to_edge_graph(), backend),
-            stream_dtype,
+            params, feats, src, dst, inv, slot, sg.num_nodes,
+            _make_agg(sg.to_edge_graph(), backend), stream_dtype,
         )
         out[sg.global_ids[: sg.num_core]] = np.asarray(pred)[: sg.num_core]
     return out

@@ -139,7 +139,7 @@ class PipelineResult:
     # concurrently.
     plan_cache: dict = dataclasses.field(default_factory=dict)
     # streaming-executor probes for partitioned runs: compiles, launches,
-    # bytes_h2d, pack/device/wall seconds, peak_packed_memory_bytes (the
+    # bytes_h2d, pack/launch/wall seconds, peak_packed_memory_bytes (the
     # modeled bytes of the largest capacity-slot launch — the quantity
     # that must fit the device budget), chosen_k.
     exec_stats: dict = dataclasses.field(default_factory=dict)
@@ -406,7 +406,7 @@ def infer_streaming(
     """Partitioned inference through the streaming executor.
 
     Returns ``(pred, exec_stats)`` where ``exec_stats`` carries the
-    executor probes (compiles, launches, bytes_h2d, pack/device/wall
+    executor probes (compiles, launches, bytes_h2d, pack/launch/wall
     seconds) plus ``peak_packed_memory_bytes`` — the modeled device bytes
     of the largest packed launch — and ``chosen_k``.
 
@@ -486,15 +486,14 @@ def verify_prepared(
     bits = prep.design.n_pi // 2
     if signed is None:
         signed = prep.cfg.dataset == "booth" or prep.design.name.startswith("booth")
-    with span("pipeline.verify_prepared", bits=bits):
-        REGISTRY.counter("pipeline.verifications").inc()
-        return verify(
-            prep.design,
-            pred[: prep.design.num_nodes],
-            bits=bits,
-            signed=signed,
-            simulate=bits <= 64,
-        )
+    REGISTRY.counter("pipeline.verifications").inc()
+    return verify(
+        prep.design,
+        pred[: prep.design.num_nodes],
+        bits=bits,
+        signed=signed,
+        simulate=bits <= 64,
+    )
 
 
 def run_pipeline(

@@ -27,6 +27,7 @@ import dataclasses
 import numpy as np
 
 from repro.core import aig as A
+from repro.obs import span
 
 
 @dataclasses.dataclass
@@ -181,15 +182,19 @@ def simulation_check(aig: A.AIG, bits: int, signed: bool, n_vectors: int = 256, 
 
 def verify(aig: A.AIG, pred: np.ndarray, *, bits: int, signed: bool = False,
            simulate: bool = True) -> VerifyResult:
-    adders, coverage = extract_adders(aig, pred)
-    n_xor = int(((pred == A.LABEL_XOR) & (aig.kind == A.AND)).sum())
-    n_maj = int(((pred == A.LABEL_MAJ) & (aig.kind == A.AND)).sum())
-    terms = algebraic_reduction_terms(adders)
+    with span("verdict.extract", bits=bits):
+        adders, coverage = extract_adders(aig, pred)
+        n_xor = int(((pred == A.LABEL_XOR) & (aig.kind == A.AND)).sum())
+        n_maj = int(((pred == A.LABEL_MAJ) & (aig.kind == A.AND)).sum())
+        terms = algebraic_reduction_terms(adders)
     if coverage < 0.999:
         status = "inconclusive"
         detail = f"adder extraction covered {coverage:.2%} of compressor tree"
     else:
-        ok = simulation_check(aig, bits, signed) if simulate else True
+        ok = True
+        if simulate:
+            with span("verdict.simulate", bits=bits):
+                ok = simulation_check(aig, bits, signed)
         status = "verified" if ok else "falsified"
         detail = "bit-flow conserved; simulation agreed" if ok else "simulation mismatch"
     return VerifyResult(

@@ -56,7 +56,7 @@ class StreamStats:
     launches: int = 0             # device calls
     bytes_h2d: int = 0            # staged host->device transfer bytes
     pack_s: float = 0.0           # host packing time (prefetch thread)
-    device_s: float = 0.0         # device execution + readback time
+    launch_s: float = 0.0         # host clock around each launch and its readback
     wall_s: float = 0.0           # end-to-end streamed time
     max_queue_depth: int = 0      # prefetch occupancy high-water mark
     # failure-domain counters: launches replayed at reduced pack capacity
@@ -72,8 +72,8 @@ class StreamStats:
 
     @property
     def overlap_s(self) -> float:
-        """Host pack time hidden behind device execution."""
-        return max(0.0, self.pack_s + self.device_s - self.wall_s)
+        """Host pack time hidden behind the launches."""
+        return max(0.0, self.pack_s + self.launch_s - self.wall_s)
 
     def delta(self, before: "StreamStats") -> "StreamStats":
         """Per-run view: this (cumulative) snapshot minus ``before``.
@@ -88,7 +88,7 @@ class StreamStats:
             launches=self.launches - before.launches,
             bytes_h2d=self.bytes_h2d - before.bytes_h2d,
             pack_s=self.pack_s - before.pack_s,
-            device_s=self.device_s - before.device_s,
+            launch_s=self.launch_s - before.launch_s,
             wall_s=self.wall_s - before.wall_s,
             capacity_halvings=self.capacity_halvings - before.capacity_halvings,
             resumed_partitions=self.resumed_partitions - before.resumed_partitions,
@@ -428,13 +428,13 @@ class StreamingExecutor:
             )
             pred = self.runner(batch.arrays)
         dt = time.perf_counter() - t0
-        self.stats.device_s += dt
+        self.stats.launch_s += dt
         self.stats.launches += 1
         self.stats.batches += 1
         self.stats.partitions += len(batch.items)
         self.stats.core_rows += scatter_core_predictions(out, batch, pred)
         REGISTRY.counter("exec.launches").inc()
-        REGISTRY.histogram("exec.device_s").observe(dt)
+        REGISTRY.histogram("exec.launch_s").observe(dt)
         if journal is not None:
             # commit core predictions partition-by-partition AFTER the
             # scatter: each journal file is written atomically, so a crash

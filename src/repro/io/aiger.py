@@ -40,7 +40,7 @@ from repro.core import aig as A
 from repro.obs import REGISTRY, span
 
 __all__ = [
-    "dump", "dumps", "load", "loads", "structural_hash",
+    "dump", "dumps", "load", "loads", "structural_digest", "structural_hash",
     "AigerError", "AigerParseError",
 ]
 
@@ -425,6 +425,13 @@ def structural_hash(design: Union[A.AIG, bytes]) -> str:
     on-disk format it arrived in.  Raw AIGER bytes are normalised by a
     parse -> re-encode round trip.
     """
+    return structural_digest(design)[0]
+
+
+def structural_digest(design: Union[A.AIG, bytes]) -> tuple[str, int]:
+    """:func:`structural_hash` and the length in bytes of the encoding it
+    hashes."""
     if isinstance(design, (bytes, bytearray)):
         design = loads(bytes(design))
-    return hashlib.sha256(dumps(design, binary=True, comments=False)).hexdigest()
+    enc = dumps(design, binary=True, comments=False)
+    return hashlib.sha256(enc).hexdigest(), len(enc)

@@ -72,6 +72,7 @@ def fused_ld_matmul(
         ],
         out_specs=pl.BlockSpec((r_t, h_t), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r_pad, h_pad), jnp.float32),
+        name="groot_ld_fused",
         interpret=interpret,
     )(msgs, w_mat)
 
@@ -142,6 +143,7 @@ def fused_ld_matmul_grouped(
         ],
         out_specs=pl.BlockSpec((r_t, h_t), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r_pad, h_pad), jnp.float32),
+        name="groot_ld_fused_grouped",
         interpret=interpret,
     )(msgs, wg.astype(msgs.dtype), w_stack.astype(jnp.float32))
 

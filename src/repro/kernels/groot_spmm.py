@@ -362,6 +362,7 @@ def ld_bucket_apply(
             ],
             out_specs=pl.BlockSpec((r_t, F_TILE), lambda i, j: (i, j)),
             out_shape=out_shape,
+            name="groot_ld_mxu",
             interpret=interpret,
         )(jnp.asarray(red, msgs.dtype), msgs)
     return pl.pallas_call(
@@ -370,6 +371,7 @@ def ld_bucket_apply(
         in_specs=[pl.BlockSpec((r_t * deg, F_TILE), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((r_t, F_TILE), lambda i, j: (i, j)),
         out_shape=out_shape,
+        name="groot_ld",
         interpret=interpret,
     )(msgs)
 
@@ -432,6 +434,7 @@ def hd_apply(
         _hd_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_hd_rows, 1, f_pad), jnp.float32),
+        name="groot_hd",
         interpret=interpret,
     )(jnp.asarray(chunk_meta.reshape(-1)), msgs)
     return out.reshape(n_hd_rows, f_pad)
@@ -498,7 +501,8 @@ def apply_plan(
     PROBE["stream_bytes"] += plan.num_slots * f_pad * x.dtype.itemsize
 
     def gather(cols: np.ndarray, eids: np.ndarray) -> jax.Array:
-        g = jnp.take(x_p, jnp.asarray(cols), axis=0)
+        with jax.named_scope("gnn.gather"):
+            g = jnp.take(x_p, jnp.asarray(cols), axis=0)
         if w_p is not None:
             g = g * jnp.take(w_p, jnp.asarray(eids), axis=0)[:, None]
         return g
@@ -599,6 +603,7 @@ def ld_grouped_apply(
             ],
             out_specs=pl.BlockSpec((g, r_t, F_TILE), lambda i, j: (0, i, j)),
             out_shape=out_shape,
+            name="groot_ld_grouped_mxu",
             interpret=interpret,
         )(jnp.asarray(red, msgs.dtype), wg.astype(msgs.dtype), msgs)
     return pl.pallas_call(
@@ -610,6 +615,7 @@ def ld_grouped_apply(
         ],
         out_specs=pl.BlockSpec((g, r_t, F_TILE), lambda i, j: (0, i, j)),
         out_shape=out_shape,
+        name="groot_ld_grouped",
         interpret=interpret,
     )(wg, msgs)
 
@@ -672,6 +678,7 @@ def hd_grouped_apply(
         _hd_kernel_grouped,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_hd_rows, g, f_pad), jnp.float32),
+        name="groot_hd_grouped",
         interpret=interpret,
     )(jnp.asarray(chunk_meta.reshape(-1)), wg, msgs)
     return jnp.transpose(out, (1, 0, 2))
@@ -756,14 +763,16 @@ def apply_plan_grouped_staged(
     PROBE["stream_bytes"] += plan.num_slots * f_pad * x_p.dtype.itemsize
     parts = []
     for b, wge in zip(plan.buckets, staged.buckets):
-        msgs = jnp.take(x_p, jnp.asarray(b.cols), axis=0)
+        with jax.named_scope("gnn.gather"):
+            msgs = jnp.take(x_p, jnp.asarray(b.cols), axis=0)
         parts.append(
             ld_grouped_apply(
                 msgs, wge, b.deg, b.rows_per_tile, interpret=interpret, mxu=mxu
             )
         )
     if plan.hd is not None:
-        msgs = jnp.take(x_p, jnp.asarray(plan.hd.cols), axis=0)
+        with jax.named_scope("gnn.gather"):
+            msgs = jnp.take(x_p, jnp.asarray(plan.hd.cols), axis=0)
         parts.append(
             hd_grouped_apply(
                 msgs, staged.hd, plan.hd.chunk_meta, len(plan.hd.rows), plan.e_t,

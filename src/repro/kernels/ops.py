@@ -262,7 +262,8 @@ def _apply_plan_fused(plan: SpmmPlan, x, w, w_mat, *, interpret: bool):
     wm_p = jnp.pad(w_mat.astype(jnp.float32), ((0, f_extra), (0, h_extra)))
 
     def gather(cols, eids):
-        g = jnp.take(x_p, jnp.asarray(cols), axis=0)
+        with jax.named_scope("gnn.gather"):
+            g = jnp.take(x_p, jnp.asarray(cols), axis=0)
         if w_p is not None:
             g = g * jnp.take(w_p, jnp.asarray(eids), axis=0)[:, None]
         return g
@@ -304,14 +305,16 @@ def _apply_plan_fused_grouped_staged(
     PROBE["stream_bytes"] += plan.num_slots * f_pad * x_p.dtype.itemsize
     parts = []
     for b, wge in zip(plan.buckets, staged.buckets):
-        msgs = jnp.take(x_p, jnp.asarray(b.cols), axis=0)
+        with jax.named_scope("gnn.gather"):
+            msgs = jnp.take(x_p, jnp.asarray(b.cols), axis=0)
         parts.append(
             fused_ld_matmul_grouped(
                 msgs, wge, wm_p, b.deg, b.rows_per_tile, interpret=interpret
             )
         )
     if plan.hd is not None:
-        msgs = jnp.take(x_p, jnp.asarray(plan.hd.cols), axis=0)
+        with jax.named_scope("gnn.gather"):
+            msgs = jnp.take(x_p, jnp.asarray(plan.hd.cols), axis=0)
         red = hd_grouped_apply(
             msgs, staged.hd, plan.hd.chunk_meta, len(plan.hd.rows), plan.e_t,
             interpret=interpret,

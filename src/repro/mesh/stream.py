@@ -347,8 +347,8 @@ class ShardedStreamingExecutor:
                 on_retry=_retried,
             )
         dt = time.perf_counter() - t0
-        self.stats.device_s += dt
-        REGISTRY.histogram("mesh.device_s").observe(dt)
+        self.stats.launch_s += dt
+        REGISTRY.histogram("mesh.launch_s").observe(dt)
         for d in active:
             batch, pred = staged[d], preds[d]
             self.stats.launches += 1

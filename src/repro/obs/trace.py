@@ -22,10 +22,22 @@ the stack is permanently instrumented.
         with span("parse"):
             ...
     tracer.save("trace.json")           # chrome://tracing-loadable
+
+While a real tracer is active its spans are also written to the JAX
+profiler's own trace: each span opens a ``jax.profiler.TraceAnnotation``
+named ``repro.<span name>`` on its thread, so a device trace taken around
+the same work carries the program's spans on the device's clock (the
+benchmark's own ``bench.*`` annotations keep their prefix).  Garbage
+collections are spans too: while any real tracer is active, a
+``gc.callbacks`` hook records one ``gc`` span per collection (attrs
+``generation`` and ``collected``) on the collecting thread, parented
+under whatever span is open there.  With no real tracer active neither
+JAX nor ``gc.callbacks`` is touched.
 """
 from __future__ import annotations
 
 import dataclasses
+import gc
 import json
 import os
 import threading
@@ -33,6 +45,30 @@ import time
 from typing import Optional
 
 _ACTIVE = threading.local()           # .tracer: the thread's current Tracer
+
+#: prefix of the profiler annotation each span opens (never ``bench.``,
+#: which the benchmark reserves for its own annotations)
+ANNOTATION_PREFIX = "repro."
+#: name of the span recorded for each garbage collection
+GC_SPAN = "gc"
+
+_annotation_cls = None                 # jax.profiler.TraceAnnotation, once imported
+
+
+def _annotation_type():
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls
+
+
+def _annotation(name: str):
+    """An entered profiler annotation for span ``name``."""
+    ann = _annotation_type()(ANNOTATION_PREFIX + name)
+    ann.__enter__()
+    return ann
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,7 +142,7 @@ def span(name: str, **attrs):
 class _SpanCtx:
     """Context manager recording one span on enter/exit."""
 
-    __slots__ = ("_tracer", "name", "attrs", "span_id", "parent_id", "_t0")
+    __slots__ = ("_tracer", "name", "attrs", "span_id", "parent_id", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -115,6 +151,7 @@ class _SpanCtx:
         self.span_id = None
         self.parent_id = None
         self._t0 = 0.0
+        self._ann = None
 
     def set(self, **attrs) -> None:
         """Attach attributes mid-span (e.g. the routing mode, an accuracy)."""
@@ -126,11 +163,13 @@ class _SpanCtx:
         self.parent_id = stack[-1] if stack else None
         self.span_id = tr._new_id()
         stack.append(self.span_id)
+        self._ann = _annotation(self.name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._ann.__exit__(None, None, None)
         tr = self._tracer
         stack = tr._stack()
         if stack and stack[-1] == self.span_id:
@@ -151,6 +190,50 @@ class _SpanCtx:
         return False
 
 
+_gc_lock = threading.Lock()
+_gc_users = 0                          # live activations of real tracers
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: a ``gc`` span on the collecting thread's
+    active tracer, if it has one."""
+    tracer = getattr(_ACTIVE, "tracer", None)
+    if tracer is None:
+        return
+    tls = tracer._tls
+    if phase == "start":
+        stack = getattr(tls, "stack", None)
+        tls.gc = (stack[-1] if stack else None, _annotation(GC_SPAN),
+                  time.perf_counter())
+        return
+    opened = getattr(tls, "gc", None)
+    if opened is None:             # the tracer became active mid-collection
+        return
+    t1 = time.perf_counter()
+    tls.gc = None
+    parent_id, ann, t0 = opened
+    ann.__exit__(None, None, None)
+    th = threading.current_thread()
+    tracer._record(Span(
+        span_id=tracer._new_id(), parent_id=parent_id, name=GC_SPAN, t0=t0, t1=t1,
+        tid=th.ident or 0, thread=th.name,
+        attrs={"generation": info["generation"], "collected": info["collected"]},
+    ))
+
+
+def _gc_hook(add: bool) -> None:
+    """Count one activation in or out; the hook sits in ``gc.callbacks``
+    exactly while at least one real tracer is active somewhere."""
+    global _gc_users
+    _annotation_type()             # never import JAX inside a collection
+    with _gc_lock:
+        _gc_users += 1 if add else -1
+        if add and _gc_users == 1:
+            gc.callbacks.append(_on_gc)
+        elif not add and _gc_users == 0:
+            gc.callbacks.remove(_on_gc)
+
+
 class _Activate:
     """Sets/restores the thread's current tracer (optionally seeding a
     cross-thread parent for :meth:`Tracer.adopt`)."""
@@ -162,6 +245,7 @@ class _Activate:
         self._parent = parent_id
 
     def __enter__(self):
+        _gc_hook(True)
         self._prev_tracer = getattr(_ACTIVE, "tracer", None)
         _ACTIVE.tracer = self._tracer
         if self._parent is not None:
@@ -178,6 +262,7 @@ class _Activate:
         _ACTIVE.tracer = self._prev_tracer
         if self._parent is not None:
             self._tracer._tls.stack = self._prev_stack or []
+        _gc_hook(False)
         return False
 
 
@@ -193,7 +278,9 @@ class Tracer:
         #: can be anchored to wall-clock time
         self.epoch_perf = time.perf_counter()
         self.epoch_wall = time.time()
-        self._lock = threading.Lock()
+        # re-entrant: a collection triggered while this thread holds the
+        # lock records its gc span through the same lock
+        self._lock = threading.RLock()
         self._spans: list[Span] = []
         self._next = 0
         self._tls = threading.local()

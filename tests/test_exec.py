@@ -213,7 +213,7 @@ def test_stream_stats_probe_counters(rand_params, csa12):
     assert s.batches == s.launches == len(plan.schedule(2))
     assert s.partitions == plan.num_parts
     assert s.core_rows == g.num_nodes             # scatter is complete
-    assert s.bytes_h2d > 0 and s.pack_s >= 0.0 and s.device_s > 0.0
+    assert s.bytes_h2d > 0 and s.pack_s >= 0.0 and s.launch_s > 0.0
 
 
 def test_prefetch_thread_error_propagates(rand_params, csa12):

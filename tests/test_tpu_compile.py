@@ -8,7 +8,9 @@ cases compile every kernel of the verification path with
 width the model pads to (F_pad = 128), the group counts of the two
 aggregation directions (G = 4 fanin, 2 fanout), LD degrees across the
 bucket range, and HD plans at 256 and 4,096 chunks (a ``csa:1024`` fanout
-plan needs 4,096).
+plan needs 4,096).  Each compiled kernel is checked to carry its name:
+``groot_ld*`` for the low-degree kernels, ``groot_hd*`` for the
+high-degree ones.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every xdist worker imports this
@@ -17,6 +19,7 @@ file.  All cases live in this one file so one worker holds the library.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -135,5 +138,9 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, groups, variant):
         builds = [_fused(deg, groups) for deg in DEGREES]
     else:
         builds = [_hd(variant, groups)]
+    # every kernel carries its pallas_call name: groot_ld* or groot_hd*
+    prefix = "groot_hd" if kernel.startswith("hd") else "groot_ld"
     for fn, shapes in builds:
-        _compile(fn, shapes, one_chip)
+        text = _compile(fn, shapes, one_chip)
+        names = re.findall(r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+        assert names and all(n.startswith(prefix) for n in names), names
