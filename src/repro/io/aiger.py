@@ -81,10 +81,11 @@ def _var_map(aig: A.AIG) -> tuple[np.ndarray, np.ndarray]:
     return var, and_nodes
 
 
-def _to_aiger_lit(var: np.ndarray, lit: int) -> int:
-    if lit < 0:
+def _to_aiger_lit(var: np.ndarray, lit: Union[int, np.ndarray]):
+    """AIGER literal of a node literal, or of each one in an array."""
+    if np.any(lit < 0):
         raise AigerError("constant literals are folded at build time; cannot export")
-    return 2 * int(var[lit >> 1]) + (lit & 1)
+    return 2 * var[lit >> 1] + (lit & 1)
 
 
 def _label_string(aig: A.AIG, and_nodes: np.ndarray) -> str:
@@ -95,11 +96,24 @@ def _label_string(aig: A.AIG, and_nodes: np.ndarray) -> str:
     return "".join(chr(ord("0") + int(v)) for v in ordered)
 
 
-def _encode_leb(delta: int, out: bytearray) -> None:
-    while delta >= 0x80:
-        out.append((delta & 0x7F) | 0x80)
-        delta >>= 7
-    out.append(delta)
+def _encode_leb(deltas: np.ndarray) -> bytes:
+    """LEB128 of each non-negative delta, concatenated in order.
+
+    Each delta becomes one 32- or 64-bit word whose byte j, in the
+    little-endian order of the hosts this runs on, is its 7-bit group j.
+    A delta keeps its bytes up to its highest non-zero group (byte 0
+    always), and each kept byte but the last carries the continuation
+    bit.  Literals stay below ``2 * num_nodes``, so eight groups suffice.
+    """
+    width = max(1, (int(deltas.max(initial=0)).bit_length() + 6) // 7)
+    d = deltas.astype(np.uint32 if width <= 4 else np.uint64)
+    word = d & 0x7F
+    keep = np.ones_like(d)  # byte j is 1 where group j is kept
+    for j in range(1, width):
+        word |= ((d >> 7 * j) & 0x7F) << 8 * j
+        keep |= (d >= 1 << 7 * j).astype(d.dtype) << 8 * j
+    word |= (keep >> 8) << 7  # continuation bit: the next byte is kept
+    return np.compress(keep.view(np.bool_), word.view(np.uint8)).tobytes()
 
 
 def dumps(aig: A.AIG, *, binary: bool = True, comments: bool = True) -> bytes:
@@ -118,15 +132,16 @@ def dumps(aig: A.AIG, *, binary: bool = True, comments: bool = True) -> bytes:
     for o in outputs:
         buf += b"%d\n" % o
     if binary:
-        for k, node in enumerate(and_nodes):
-            lhs = 2 * (aig.n_pi + 1 + k)
-            r0 = _to_aiger_lit(var, int(aig.fanin0[node]))
-            r1 = _to_aiger_lit(var, int(aig.fanin1[node]))
-            rhs0, rhs1 = max(r0, r1), min(r0, r1)
-            if rhs0 >= lhs:
-                raise AigerError("AND fanins are not topologically ordered")
-            _encode_leb(lhs - rhs0, buf)
-            _encode_leb(rhs0 - rhs1, buf)
+        lhs = 2 * (aig.n_pi + 1 + np.arange(n_and, dtype=np.int64))
+        r0 = _to_aiger_lit(var, aig.fanin0[and_nodes])
+        r1 = _to_aiger_lit(var, aig.fanin1[and_nodes])
+        rhs0, rhs1 = np.maximum(r0, r1), np.minimum(r0, r1)
+        if np.any(rhs0 >= lhs):
+            raise AigerError("AND fanins are not topologically ordered")
+        deltas = np.empty(2 * n_and, dtype=np.int64)
+        deltas[0::2] = lhs - rhs0
+        deltas[1::2] = rhs0 - rhs1
+        buf += _encode_leb(deltas)
     else:
         for k, node in enumerate(and_nodes):
             lhs = 2 * (aig.n_pi + 1 + k)
