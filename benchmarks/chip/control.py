@@ -5,7 +5,9 @@
 
 For each seed, in one process: the weights are made as in a run, and the
 timed path answers one request on each of two sides, read with the numbers
-of ``checks.py`` against the float32 reference at highest precision:
+of ``checks.py`` against the float32 reference at highest precision that
+``checks.References`` holds the request to (the full graph, or the re-grown
+subgraphs of the request's partition):
 
   program  the system as the cell runs it (the lower readings)
   control  the system with its own bfloat16 edge-stream path switched on
@@ -53,12 +55,7 @@ def readings(name: str, seeds, *, require_chip: bool = True, shrink=None):
     sessions = {}
     for seed in seeds:
         params = jax.block_until_ready(harness.make_params(config, seed))
-        t0 = time.perf_counter()
-        ref = checks.reference_logits(config, traffic, params)
-        top2 = np.sort(ref, axis=1)[:, -2:]
-        base = {"cell": name, "seed": seed, "ref_s": time.perf_counter() - t0,
-                "min_margin": float((top2[:, 1] - top2[:, 0]).min()),
-                "accuracy": float((ref.argmax(1) == traffic.design["label"]).mean())}
+        refs = checks.References(config, traffic, params)
         for side, cfg in sides.items():
             if side not in sessions:
                 sessions[side] = harness.make_session(traffic, cfg, params, False)
@@ -67,12 +64,22 @@ def readings(name: str, seeds, *, require_chip: bool = True, shrink=None):
             t0 = time.perf_counter()
             res = harness.request(sessions[side], traffic, design)
             t_req = time.perf_counter() - t0
-            gap, mismatch = checks.class_numbers(ref, res.predictions, traffic.batch)
-            yield {**base, "side": side, "logit_gap_max": gap,
-                   "class_mismatch_share": mismatch,
-                   "verdict_fields_off": checks.verdict_fields_off(config, traffic, res, {}),
+            t0 = time.perf_counter()
+            ref = refs.for_result(res)
+            row = {"cell": name, "seed": seed, "side": side, "route": res.routing.mode,
+                   "k": res.routing.k, "ref_s": time.perf_counter() - t0,
                    "request_s": t_req, "status": getattr(res.verdict, "status", None),
                    "coverage": getattr(res.verdict, "coverage", None)}
+            if ref is None:             # the check cannot judge this result
+                yield {**row, "judged": False}
+                continue
+            top2 = np.sort(ref, axis=1)[:, -2:]
+            gap, mismatch = checks.class_numbers(ref, res.predictions, traffic.batch)
+            yield {**row, "judged": True, "logit_gap_max": gap,
+                   "class_mismatch_share": mismatch,
+                   "verdict_fields_off": checks.verdict_fields_off(config, traffic, res, {}),
+                   "min_margin": float((top2[:, 1] - top2[:, 0]).min()),
+                   "accuracy": float((ref.argmax(1) == traffic.design["label"]).mean())}
     for sess in sessions.values():
         sess.close()
 
